@@ -30,6 +30,9 @@ def main() -> None:
     ap.add_argument("--rows", type=int, default=None, help="bench store size")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from . import (
         bench_ingest_scaling,
         bench_kernels,

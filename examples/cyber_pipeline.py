@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import And, Eq, EventStore, QueryProcessor, QueryStats, web_proxy_schema
 from repro.models import get_config, init_params
 from repro.models.model import forward_train
@@ -28,6 +29,7 @@ from repro.training.optimizer import OptConfig, adamw_init, adamw_update
 
 
 def main():
+    use_compile_cache()
     print("== 1. stage raw logs ==")
     src = SyntheticWebProxySource(seed=11)
     staged = src.write_files(tempfile.mkdtemp(), n_files=6, lines_per_file=5000, t_start=0, t_stop=4 * 3600)

@@ -14,6 +14,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     AggregateSpec,
     And,
@@ -28,6 +29,7 @@ from repro.pipeline.sources import SyntheticWebProxySource, parse_web_proxy_line
 
 
 def main():
+    use_compile_cache()
     print("== build store (8 shards, as the paper's 8-node instance) ==")
     store = EventStore(web_proxy_schema(), n_shards=8)
     src = SyntheticWebProxySource(seed=1)

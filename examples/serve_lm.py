@@ -14,11 +14,13 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.models import get_config, init_params
 from repro.serving import AdaptiveRequestBatcher, ServeEngine
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("llcysa-analytics-100m", smoke=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
     batcher = AdaptiveRequestBatcher(k0=1, c=1.5, t_min=0.02, t_max=0.25, max_batch=8)

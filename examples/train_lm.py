@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.checkpointing import CheckpointManager
 from repro.core import EventStore, web_proxy_schema
 from repro.models import get_config, init_params
@@ -30,6 +31,7 @@ from repro.training.optimizer import OptConfig, adamw_init, adamw_update
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=["mini", "100m"], default="mini")
     ap.add_argument("--steps", type=int, default=200)

@@ -12,8 +12,9 @@ programs over device-resident state:
              tablet picks its rows out of the replicated batch and
              scatter-appends them into its memtable slab
     minor    per-tablet memtable sort into the next sorted-run slot
-    major    k-way merge of runs + base via the merge_runs rank kernel
-             (kernels/merge_runs, Pallas on TPU / jnp reference on CPU) —
+    major    k-way merge of runs + base (kernels/merge_runs: one
+             stable sort, or the Pallas rank kernel when a caller asks
+             for kernel_backend="pallas") —
              BLOCKING the writer that tripped it, which is the paper's
              backpressure, reproduced on the mesh
 
@@ -90,6 +91,7 @@ from __future__ import annotations
 
 import itertools
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -97,14 +99,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import keypack
 from .dist_query import DistStore
 from .ingest import BatchWriter, IngestMetrics, check_shard_guidance
 from .store import DEFAULT_AGG_BUCKET_SECONDS
-from ..kernels.merge_runs.ops import _pow2
+from ..kernels.merge_runs.ops import _pow2, sort_with_payload
 from ..obs import MetricsRegistry, OwnedLock, span
 
 REV_PAD = np.iinfo(np.int32).max  # +inf rev_ts sentinel (matches DistStore)
@@ -148,15 +150,25 @@ def _combine_dup_keys(keys, vals, sentinel):
     """Sum payloads of equal adjacent keys in a sorted (sentinel-tailed)
     sequence and compact the unique keys to the front — the traceable form
     of tables.py::_combine_sorted, used for the aggregate family's
-    combiner-on-compaction. Returns (ukeys, usums, n_unique)."""
+    combiner-on-compaction (and, with vals ignored, the index family's
+    dedup). Returns (ukeys, usums, n_unique); usums is zero past n_unique.
+
+    Gather- and scatter-free (a TPU runs both far slower than a sort at
+    tablet sizes): the last entry of each run of equal keys keeps its key
+    and the running sum of vals, every other entry becomes the sentinel,
+    and one stable sort moves the survivors to the front in key order —
+    each segment's sum is then the difference of neighbouring running
+    sums."""
     n = keys.shape[0]
-    is_head = jnp.concatenate([jnp.ones((1,), bool), keys[1:] != keys[:-1]])
-    seg = jnp.cumsum(is_head.astype(jnp.int32)) - 1
-    sums = jax.ops.segment_sum(vals.astype(jnp.int64), seg, num_segments=n)
-    n_unique = (is_head & (keys != sentinel)).sum(dtype=jnp.int32)
-    # All members of a segment carry the same key, so the duplicate-index
-    # scatter is idempotent; the sentinel segment (if any) is the last.
-    ukeys = jnp.full((n,), sentinel, keys.dtype).at[seg].set(keys)
+    is_tail = jnp.concatenate([keys[1:] != keys[:-1], jnp.ones((1,), bool)])
+    keep = is_tail & (keys != sentinel)
+    n_unique = keep.sum(dtype=jnp.int32)
+    running = jnp.cumsum(vals.astype(jnp.int64))
+    ukeys, urun = lax.sort(
+        (jnp.where(keep, keys, sentinel), running), num_keys=1, is_stable=True
+    )
+    sums = urun - jnp.concatenate([jnp.zeros((1,), jnp.int64), urun[:-1]])
+    sums = jnp.where(jnp.arange(n) < n_unique, sums, 0)
     return ukeys, sums, n_unique
 
 
@@ -166,9 +178,7 @@ def _sort_masked(keys, cols, n, sentinel):
     before the last flush. Shared by minor compaction and the publish seal
     so both produce the same sorted, sentinel-tailed level layout."""
     valid = jnp.arange(keys.shape[0], dtype=jnp.int32) < n
-    masked = jnp.where(valid, keys, sentinel)
-    order = jnp.argsort(masked)
-    return masked[order], cols[order]
+    return sort_with_payload(jnp.where(valid, keys, sentinel), cols)
 
 
 class _PlanePrograms:
@@ -257,6 +267,50 @@ class _PlanePrograms:
     def _specs(self, names) -> Dict[str, P]:
         return {n: self._spec_of(n) for n in names}
 
+    def _shardings(self, specs: Dict[str, P]) -> Dict[str, NamedSharding]:
+        """Pinned output shardings: every program hands back its state
+        with exactly the sharding the state was created with (jit would
+        otherwise canonicalise some, e.g. to P() on a one-device mesh),
+        so a step's next call hits the executable it already compiled."""
+        return {n: NamedSharding(self.mesh, sp) for n, sp in specs.items()}
+
+    def state_layout(self) -> Dict[str, Tuple[Tuple[int, ...], np.dtype, int]]:
+        """(shape, dtype, fill) of every per-group state array: what a
+        TabletGroup allocates, and the shapes an ahead-of-time compile
+        for a described chip hands the step programs (abstract_state)."""
+        t, k = self.n_tablets, self.max_runs
+        i32, i64 = np.dtype(np.int32), np.dtype(np.int64)
+        out = {
+            "n_runs": ((t,), i32, 0),
+            "rows": ((t,), i64, 0),
+            "minor": ((t,), i32, 0),
+            "major": ((t,), i32, 0),
+        }
+        for f in self.families:
+            p, m, c = f.name, f.mem_rows, f.capacity
+            out[f"{p}_mem_k"] = ((t, m), f.key_dtype, 0)
+            out[f"{p}_mem_c"] = ((t, m, f.width), f.col_dtype, 0)
+            out[f"{p}_mem_n"] = ((t,), i32, 0)
+            out[f"{p}_run_k"] = ((t, k, m), f.key_dtype, f.sentinel)
+            out[f"{p}_run_c"] = ((t, k, m, f.width), f.col_dtype, 0)
+            out[f"{p}_run_n"] = ((t, k), i32, 0)
+            out[f"{p}_base_k"] = ((t, c), f.key_dtype, f.sentinel)
+            out[f"{p}_base_c"] = ((t, c, f.width), f.col_dtype, 0)
+            out[f"{p}_base_n"] = ((t,), i32, 0)
+            out[f"{p}_overflow"] = ((t,), i32, 0)
+        return out
+
+    def abstract_state(self, names) -> Dict[str, jax.ShapeDtypeStruct]:
+        """Shapes + shardings (no allocation) of the named state arrays."""
+        layout = self.state_layout()
+        return {
+            n: jax.ShapeDtypeStruct(
+                layout[n][0], layout[n][1],
+                sharding=NamedSharding(self.mesh, self._spec_of(n)),
+            )
+            for n in names
+        }
+
     # --------------------------------------------------------- name lists
     def _append_names(self):
         names = ["rows"]
@@ -290,6 +344,16 @@ class _PlanePrograms:
             p = f.name
             names += [f"{p}_mem_k", f"{p}_mem_c", f"{p}_mem_n"]
         return names
+
+    def seal_buckets(self) -> Tuple[int, ...]:
+        """Every seal_rows value _seal_bucket can return: 8, 16, ... up to
+        mem_rows (the last possibly not a power of two)."""
+        out, rows = [], 8
+        while True:
+            out.append(min(rows, self.mem_rows))
+            if rows >= self.mem_rows:
+                return tuple(out)
+            rows *= 2
 
     def _seal_bucket(self, fill_max: int) -> int:
         """Event-family slot count the seal program must sort to cover a
@@ -325,6 +389,28 @@ class _PlanePrograms:
         return self._get_step(
             ("seal", seal_rows), lambda: self._build_seal(seal_rows)
         )
+
+    def precompile(self) -> None:
+        """Compile the minor, fold_one and major programs and every seal
+        bucket CONCURRENTLY, ahead of their first call. XLA compiles
+        outside the GIL and a jitted function's later call with the same
+        shapes and shardings reuses the executable, so startup pays about
+        the slowest compile instead of the sum (on a v5e each seal bucket
+        and each fold program is a tens-of-seconds compile)."""
+        run_names, base_names = self._major_names()
+        folds = (self.abstract_state(run_names), self.abstract_state(base_names))
+        jobs = [
+            (self.minor_step(), (self.abstract_state(self._minor_names()),)),
+            (self.fold_one_step(), folds),
+            (self.major_step(), folds),
+        ] + [
+            (self.seal_step(r), (self.abstract_state(self._seal_names()),))
+            for r in self.seal_buckets()
+        ]
+        with ThreadPoolExecutor(len(jobs)) as pool:
+            done = [pool.submit(lambda s, a: s.lower(*a).compile(), s, a) for s, a in jobs]
+            for f in done:
+                f.result()
 
     # --------------------------------------------------------- step builders
     def _build_append(self):
@@ -405,13 +491,14 @@ class _PlanePrograms:
             mesh=mesh,
             in_specs=(self._specs(names), P(None), P(None, None), P(None)),
             out_specs=self._specs(names),
-            check_rep=False,
+            check_vma=False,
         )
         # The ONE allowed donation in the planes: the append step donates
         # only the live memtable slabs, which publish() never aliases — a
         # snapshot seals a sorted COPY of the memtable (_sort_level), so
         # no published DistStore can see the donated buffers.
-        return jax.jit(smapped, donate_argnums=(0,))  # reprolint: disable=no-donate-in-plane
+        out = self._shardings(self._specs(names))
+        return jax.jit(smapped, donate_argnums=(0,), out_shardings=out)  # reprolint: disable=no-donate-in-plane
 
     def _build_minor(self):
         mesh, k = self.mesh, self.max_runs
@@ -448,15 +535,15 @@ class _PlanePrograms:
             mesh=mesh,
             in_specs=(self._specs(names),),
             out_specs=self._specs(names),
-            check_rep=False,
+            check_vma=False,
         )
         # NOT donated: publish() hands out DistStore views of the run
         # slabs (run-aware reads), and on backends that implement donation
         # a donated minor would delete arrays a caller may still hold.
-        return jax.jit(smapped)
+        return jax.jit(smapped, out_shardings=self._shardings(self._specs(names)))
 
     def _build_major(self):
-        from ..kernels.merge_runs import merge_sorted_device
+        from ..kernels.merge_runs import merge_base_runs_device
 
         mesh, k = self.mesh, self.max_runs
         families = self.families
@@ -470,11 +557,9 @@ class _PlanePrograms:
                 out_r = dict(rloc)
                 out_b = {}
                 for f in families:
-                    p, m, c, w = f.name, f.mem_rows, f.capacity, f.width
-                    # Two-stage merge: the K runs (m rows each) first, then
-                    # the result against the base — pad both sides of the
-                    # 2-way merge to one power-of-two length.
-                    l2 = _pow2(max(c, k * m))
+                    p, m, c = f.name, f.mem_rows, f.capacity
+                    # Base + K runs (m rows each) in one merge: the base
+                    # wins ties, then earlier runs.
                     rn = rloc[f"{p}_run_n"]
                     bk, bc, bn = bloc[f"{p}_base_k"], bloc[f"{p}_base_c"], bloc[f"{p}_base_n"]
                     # Mask stale slots/rows (run_n is authoritative; slots
@@ -482,14 +567,7 @@ class _PlanePrograms:
                     within = jnp.arange(m, dtype=jnp.int32)[None, :] < rn[:, None]
                     ck = jnp.where(within, rloc[f"{p}_run_k"], f.sentinel)
                     cc = jnp.where(within[..., None], rloc[f"{p}_run_c"], 0)
-                    mk, mc = merge_sorted_device(ck, cc, backend=backend)
-                    pad_a = jnp.full((l2,), f.sentinel, mk.dtype).at[:c].set(bk)
-                    pad_b = jnp.full((l2,), f.sentinel, mk.dtype).at[: k * m].set(mk)
-                    ca = jnp.zeros((l2, w), mc.dtype).at[:c].set(bc)
-                    cb = jnp.zeros((l2, w), mc.dtype).at[: k * m].set(mc)
-                    fk, fc = merge_sorted_device(
-                        jnp.stack([pad_a, pad_b]), jnp.stack([ca, cb]), backend=backend
-                    )
+                    fk, fc = merge_base_runs_device(bk, bc, ck, cc, backend=backend)
                     if f.combine == "sum":
                         # Aggregate family: sum duplicate (field, value,
                         # bucket) keys — Accumulo's combiner at compaction
@@ -509,7 +587,7 @@ class _PlanePrograms:
                             fk, jnp.zeros(fk.shape, jnp.int32), f.sentinel
                         )
                     else:
-                        total = bn + rn.sum()
+                        total = bn + rn.sum(dtype=jnp.int32)
                     new_bn = jnp.where(do, jnp.minimum(total, jnp.int32(c)), bn)
                     lost = jnp.where(do, total - jnp.minimum(total, jnp.int32(c)), 0)
                     out_b[f"{p}_base_k"] = jnp.where(do, fk[:c], bk)
@@ -528,14 +606,16 @@ class _PlanePrograms:
             mesh=mesh,
             in_specs=(self._specs(run_names), self._specs(base_names)),
             out_specs=(self._specs(run_names), self._specs(base_names)),
-            check_rep=False,
+            check_vma=False,
         )
         # Deliberately NOT donated (neither runs nor bases): publish()
         # hands out DistStore views of run slabs AND base runs, and on
         # backends that implement donation (TPU/GPU) a donated major
         # would delete arrays a caller may still hold. Majors are rare;
         # one copy each is the price of stable published views.
-        return jax.jit(smapped)
+        return jax.jit(smapped, out_shardings=(
+            self._shardings(self._specs(run_names)), self._shardings(self._specs(base_names))
+        ))
 
     def _build_fold_one(self):
         """One INCREMENT of major compaction: every tablet folds its TOP
@@ -610,11 +690,13 @@ class _PlanePrograms:
             mesh=mesh,
             in_specs=(self._specs(run_names), self._specs(base_names)),
             out_specs=(self._specs(run_names), self._specs(base_names)),
-            check_rep=False,
+            check_vma=False,
         )
         # NOT donated, same as the full major: published views alias the
         # run/base buffers and must survive the fold.
-        return jax.jit(smapped)
+        return jax.jit(smapped, out_shardings=(
+            self._shardings(self._specs(run_names)), self._shardings(self._specs(base_names))
+        ))
 
     def _build_seal(self, seal_rows: int):
         """FILL-BOUNDED sorted snapshot of the memtables — the only
@@ -673,9 +755,9 @@ class _PlanePrograms:
             mesh=mesh,
             in_specs=(self._specs(names),),
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
-        return jax.jit(smapped)
+        return jax.jit(smapped, out_shardings=self._shardings(out_specs))
 
 
 class TabletGroup:
@@ -750,28 +832,11 @@ class TabletGroup:
 
     def _init_state(self) -> Dict[str, jax.Array]:
         pr = self.programs
-        t, k = self.n_tablets, pr.max_runs
-        host: Dict[str, np.ndarray] = {
-            "n_runs": np.zeros((t,), np.int32),
-            "rows": np.zeros((t,), np.int64),
-            "minor": np.zeros((t,), np.int32),
-            "major": np.zeros((t,), np.int32),
-        }
-        for f in pr.families:
-            p, m, c = f.name, f.mem_rows, f.capacity
-            host[f"{p}_mem_k"] = np.zeros((t, m), f.key_dtype)
-            host[f"{p}_mem_c"] = np.zeros((t, m, f.width), f.col_dtype)
-            host[f"{p}_mem_n"] = np.zeros((t,), np.int32)
-            host[f"{p}_run_k"] = np.full((t, k, m), f.sentinel, f.key_dtype)
-            host[f"{p}_run_c"] = np.zeros((t, k, m, f.width), f.col_dtype)
-            host[f"{p}_run_n"] = np.zeros((t, k), np.int32)
-            host[f"{p}_base_k"] = np.full((t, c), f.sentinel, f.key_dtype)
-            host[f"{p}_base_c"] = np.zeros((t, c, f.width), f.col_dtype)
-            host[f"{p}_base_n"] = np.zeros((t,), np.int32)
-            host[f"{p}_overflow"] = np.zeros((t,), np.int32)
         return {
-            name: jax.device_put(arr, NamedSharding(self.mesh, pr._spec_of(name)))
-            for name, arr in host.items()
+            name: jax.device_put(
+                np.full(shape, fill, dtype), NamedSharding(self.mesh, pr._spec_of(name))
+            )
+            for name, (shape, dtype, fill) in pr.state_layout().items()
         }
 
     def _sub(self, names) -> Dict[str, jax.Array]:  # holds: lock
@@ -987,12 +1052,8 @@ class TabletGroup:
     def warm_seal(self) -> None:
         with self.lock.hold("warmup"):
             pr = self.programs
-            seal_rows = 8
-            while True:
+            for seal_rows in pr.seal_buckets():
                 pr.seal_step(seal_rows)(self._sub(pr._seal_names()))
-                if seal_rows >= pr.mem_rows:
-                    break
-                seal_rows = min(seal_rows * 2, pr.mem_rows)
 
     def warm_compaction(self) -> None:
         with self.lock.hold("warmup"):
@@ -1393,6 +1454,12 @@ class DistIngestPlane:
                     },
                 )
                 return self._composite
+
+    def precompile(self) -> None:
+        """Compile every compaction and seal program concurrently (see
+        _PlanePrograms.precompile); warm_compaction() and warm_seal() then
+        only execute them. Shared by all groups: shapes are identical."""
+        self.programs.precompile()
 
     def warm_seal(self) -> None:
         """Pre-compile (and once-execute) the fill-bounded seal program

@@ -47,7 +47,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import keypack
@@ -423,7 +423,7 @@ def build_scan_step(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(), P(axes, None), P(axes, None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 
@@ -578,7 +578,7 @@ def build_aggregate_step(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 
@@ -840,7 +840,7 @@ def build_index_step(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(), P(axes, None), P(axes, None, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 
@@ -947,7 +947,7 @@ def build_index_aggregate_step(
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(None), P(None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 
@@ -1003,7 +1003,7 @@ def build_density_step(mesh: Mesh, runs: bool = False):
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped)
 

@@ -11,9 +11,13 @@ paper implements as server-side Accumulo iterators/combiners:
                      iterator stack) -> one VMEM pass per tablet tile
 
 Each subpackage: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper; on CPU defaults to the vectorized jnp reference since
-interpret-mode Pallas is an emulation, on TPU to the kernel), ref.py
-(pure-jnp oracle used for allclose validation).
+public wrapper), ref.py (pure-jnp oracle used for allclose validation).
+
+Backend policy (common.py::resolve_backend): backend="auto" is the jnp
+reference on EVERY platform — none of these kernels compiles for TPU yet
+(ROADMAP S6/S8/D2 record the compiler errors). backend="pallas" is an
+explicit request: native on TPU, interpret mode elsewhere, and a request
+past a kernel's VMEM cap raises instead of switching to the reference.
 
 All kernels operate on int32 lanes only (dictionary codes / split key
 lanes) — the packed int64 keys never enter a kernel, by design (TPU-native

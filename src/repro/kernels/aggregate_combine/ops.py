@@ -1,17 +1,18 @@
 """Public combiner op: sums counts of duplicate keys in a sorted run.
 
 Pallas path: tile-local segmented sums from the kernel + an O(n_tiles)
-stitching epilogue for keys straddling tile boundaries. CPU default: the
-jnp reference (identical output, asserted in tests)."""
+stitching epilogue for keys straddling tile boundaries. Default ("auto",
+kernels/common.py::resolve_backend): the jnp reference on every platform
+(identical output, asserted in tests) — the kernel does not compile for
+TPU (`scatter` is not implemented in Mosaic; ROADMAP D2)."""
 from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import pow2 as _pow2, split_key_lanes as _split
+from ..common import pallas_interpret, pow2 as _pow2, resolve_backend, split_key_lanes as _split
 from .aggregate_combine import BLOCK, combine_blocks_pallas
 from .ref import combine_blocks_ref
 
@@ -27,8 +28,7 @@ def combine_sorted_counts(
     if n == 0:
         return keys, counts
     hi, lo = _split(keys)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     if backend == "ref":
         # Pow2-bucket to avoid per-shape retraces. Pad keys with INT64_MAX
         # pairs and zero counts: they form trailing segments summing to 0
@@ -48,9 +48,9 @@ def combine_sorted_counts(
     lo_p = np.full(n_pad, np.iinfo(np.int32).max, np.int32)
     cnt_p = np.zeros(n_pad, np.int32)
     hi_p[:n], lo_p[:n], cnt_p[:n] = hi, lo, counts
-    interpret = jax.default_backend() != "tpu"
     heads, sums = combine_blocks_pallas(
-        jnp.asarray(hi_p), jnp.asarray(lo_p), jnp.asarray(cnt_p), interpret=interpret
+        jnp.asarray(hi_p), jnp.asarray(lo_p), jnp.asarray(cnt_p),
+        interpret=pallas_interpret(),
     )
     heads = np.asarray(heads).copy()
     sums = np.asarray(sums).copy()

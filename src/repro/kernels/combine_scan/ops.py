@@ -7,17 +7,17 @@ group that has at least one filter-surviving event: (group key, aggregate,
 match count).
 
 Pallas path: tile-local fused kernel + an O(n_tiles) stitch epilogue for
-groups straddling tile boundaries. CPU default: the jnp reference
-(identical output, asserted in tests) — same backend policy as
-filter_scan/aggregate_combine."""
+groups straddling tile boundaries. Default ("auto",
+kernels/common.py::resolve_backend): the jnp reference on every platform
+(identical output, asserted in tests) — the kernel does not compile for
+TPU (`dynamic_slice` in the shared program interpreter; ROADMAP D2)."""
 from __future__ import annotations
 
 from typing import TYPE_CHECKING, Optional, Tuple
 
-import jax
 import numpy as np
 
-from ..common import split_key_lanes
+from ..common import pallas_interpret, resolve_backend, split_key_lanes
 from ..filter_scan.ops import LANE, _bucket, _pow2, pad_program
 from ..program_eval import OP_PUSH_TRUE
 from .combine_scan import BLOCK, OP_MAX, OP_MIN, OP_SUM, combine_scan_pallas
@@ -86,8 +86,9 @@ def combine_scan(
     the server. Sum/count aggregates accumulate in int64 across tiles and
     blocks; the Pallas kernel's tile-local partials are int32, which is
     exact as long as one BLOCK-row tile cannot wrap (|value| < 2^31/BLOCK
-    per row — always true for count, whose values are 1s). Sums over
-    larger values route to the int64 jnp reference automatically.
+    per row — always true for count, whose values are 1s). An explicit
+    backend="pallas" sum over larger values raises ValueError; "auto"
+    (the int64 jnp reference) has no such bound.
     """
     op_kind = OPS[op]
     group_keys = np.asarray(group_keys, dtype=np.int64)
@@ -106,16 +107,17 @@ def combine_scan(
         prog = trivial_program()
     opc, a0, a1, cs = pad_program(prog)
     hi, lo = split_key_lanes(group_keys)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     if (
         backend == "pallas"
         and op == "sum"
         and values.size
         and int(np.abs(values, dtype=np.int64).max()) > (2**31 - 1) // BLOCK
     ):
-        # A single tile's int32 partial could wrap: use the int64 ref.
-        backend = "ref"
+        raise ValueError(
+            "combine_scan pallas: a tile's int32 partial sum could wrap for "
+            "values this large; use backend='ref' (int64)"
+        )
 
     if backend == "ref":
         # Pow2-bucket rows to bound retraces (adaptive batching varies n
@@ -142,10 +144,9 @@ def combine_scan(
         aggs = np.asarray(aggs)[:n]
         cnts = np.asarray(cnts)[:n]
     else:
-        interpret = jax.default_backend() != "tpu"
         heads, aggs, cnts = combine_scan_pallas(
             hi_p, lo_p, val_p, cols_p, opc, a0, a1, cs,
-            op_kind=op_kind, interpret=interpret,
+            op_kind=op_kind, interpret=pallas_interpret(),
         )
         heads = np.asarray(heads).copy()
         # Widen before the stitch: cross-tile accumulation must be int64

@@ -1,11 +1,36 @@
-"""Helpers shared across kernel subpackages (core-import-free: the
+"""Helpers shared across kernel subpackages — the backend policy, pow2
+bucketing, key-lane splitting (core-import-free: the
 kernels package must never import repro.core at module scope — core's
 __init__ imports the query/iterator modules that need the kernels)."""
 from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import numpy as np
+
+BACKENDS = ("ref", "pallas")
+
+
+def resolve_backend(backend: str) -> str:
+    """The one kernel backend policy every ops.py entry point follows.
+
+    "auto" resolves to the jnp reference on EVERY platform: none of the
+    Pallas kernels compiles for TPU yet (the compiler errors are recorded
+    in ROADMAP S6/S8/D2), so auto never selects one. "pallas" is an
+    explicit request — native on TPU, interpret mode elsewhere
+    (pallas_interpret) — and an explicit request that a kernel cannot
+    serve (a VMEM cap) raises instead of switching to the reference."""
+    if backend == "auto":
+        return "ref"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {backend!r}: auto | ref | pallas")
+    return backend
+
+
+def pallas_interpret() -> bool:
+    """Pallas runs natively only on a TPU; everywhere else in interpret mode."""
+    return jax.default_backend() != "tpu"
 
 
 def pow2(n: int) -> int:

@@ -1,20 +1,20 @@
 """Public entry point for the filter kernel: padding, program bucketing,
 backend dispatch.
 
-Backend policy: on TPU the Pallas kernel runs natively; on CPU (this
-container) interpret-mode Pallas is a Python emulation, so the production
-query path uses the jnp reference (identical semantics — asserted by the
-kernel test suite) and the kernel is exercised with interpret=True in
-tests."""
+Backend policy (kernels/common.py::resolve_backend): "auto" is the jnp
+reference on every platform (identical semantics — asserted by the kernel
+test suite). The kernel does not compile for TPU (`dynamic_slice` is not
+implemented in Mosaic; ROADMAP D2), so it runs only on an explicit
+backend="pallas" request — native on TPU, interpret mode elsewhere, as
+the tests exercise it."""
 from __future__ import annotations
 
 from typing import TYPE_CHECKING, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import pow2 as _pow2
+from ..common import pallas_interpret, pow2 as _pow2, resolve_backend
 from ..program_eval import OP_NOP
 
 if TYPE_CHECKING:  # runtime import would cycle: core/__init__ needs kernels
@@ -57,8 +57,7 @@ def filter_scan(
     if n == 0:
         return np.zeros(0, bool)
     opc, a0, a1, cs = pad_program(prog)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     if backend == "ref":
         # Bucket rows to powers of two: adaptive batching produces a
         # different n every call, and per-shape retracing would dominate
@@ -74,8 +73,7 @@ def filter_scan(
     f_pad = _bucket(f, LANE)
     cols_p = np.zeros((n_pad, f_pad), np.int32)
     cols_p[:n, :f] = cols
-    interpret = jax.default_backend() != "tpu"
     mask = filter_scan_pallas(
-        jnp.asarray(cols_p), opc, a0, a1, cs, interpret=interpret
+        jnp.asarray(cols_p), opc, a0, a1, cs, interpret=pallas_interpret()
     )
     return np.asarray(mask)[:n]

@@ -4,17 +4,18 @@ intersect_sorted: A ∩ B over sorted int64 packed-key vectors (the planner's
 AND path — paper Fig 2). union_sorted: A ∪ B (the OR path; bandwidth-bound
 merge, no kernel warranted — jnp sort of the concatenation).
 
-The Pallas path requires the probe set in VMEM; adaptive batching keeps
-index-scan result sets small, and ops enforces MAX_VMEM_KEYS as the
-documented cap (falls back to the reference beyond it).
+Backend policy (kernels/common.py::resolve_backend): "auto" is the jnp
+reference on every platform — the kernel does not compile for TPU
+(lowering hits a RecursionError; ROADMAP D2). An explicit "pallas"
+request needs the probe set in VMEM and raises ValueError past the
+documented MAX_VMEM_KEYS cap instead of switching to the reference.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..common import pow2 as _pow2, split_key_lanes as _split
+from ..common import pallas_interpret, pow2 as _pow2, resolve_backend, split_key_lanes as _split
 from .merge_intersect import BLOCK, intersect_mask_pallas
 from .ref import intersect_mask_ref
 
@@ -31,10 +32,12 @@ def intersect_sorted(a: np.ndarray, b: np.ndarray, backend: str = "auto") -> np.
     # Probe the smaller set from the larger: kernel cost n log m.
     if a.size < b.size:
         a, b = b, a
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if backend != "ref" and b.size > MAX_VMEM_KEYS:
-        backend = "ref"
+    backend = resolve_backend(backend)
+    if backend == "pallas" and b.size > MAX_VMEM_KEYS:
+        raise ValueError(
+            f"merge_intersect pallas: probe set of {b.size} keys exceeds the VMEM "
+            f"cap MAX_VMEM_KEYS={MAX_VMEM_KEYS}; use backend='ref'"
+        )
     a_hi, a_lo = _split(a)
     b_hi, b_lo = _split(b)
     if backend == "ref":
@@ -61,10 +64,10 @@ def intersect_sorted(a: np.ndarray, b: np.ndarray, backend: str = "auto") -> np.
     bl = np.full(m_pad, -1, np.int32)  # 0xFFFFFFFF: max in unsigned order
     bh[: b.size] = b_hi
     bl[: b.size] = b_lo
-    interpret = jax.default_backend() != "tpu"
     mask = np.asarray(
         intersect_mask_pallas(
-            jnp.asarray(ah), jnp.asarray(al), jnp.asarray(bh), jnp.asarray(bl), interpret=interpret
+            jnp.asarray(ah), jnp.asarray(al), jnp.asarray(bh), jnp.asarray(bl),
+            interpret=pallas_interpret(),
         )
     )[: a.size]
     return a[mask]

@@ -23,8 +23,7 @@ merged output carries its sentinels as a contiguous tail.
 Each count is a branchless binary-search descent over one run: log2(R)
 fori steps plus one final adjust, vectorized across a (BLOCK,) element
 tile; the full (K, R) key lanes stay VMEM-resident across the grid
-(ops.py enforces the documented VMEM cap and falls back to the jnp
-reference beyond it). Work per element is K*log2(R) compares vs log2(K*R)
+(ops.py enforces the documented VMEM cap and raises beyond it). Work per element is K*log2(R) compares vs log2(K*R)
 full data movements for the sort — and the payload columns never enter
 the kernel at all.
 """

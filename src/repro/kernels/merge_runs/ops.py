@@ -1,32 +1,48 @@
 """Public k-way sorted-run merge — major compaction's data plane.
 
 merge_sorted_runs: host entry point (numpy in/out) used by tables.py
-tablet compaction on 64-bit packed keys. merge_sorted_device: traceable
-form used per tablet inside the dist_ingest shard_map compaction program
-on 32-bit rev_ts keys. Both compute output ranks (Pallas kernel / jnp
-reference — identical results, asserted in tests) and scatter keys plus
-payload columns in one pass; the payload never enters the rank kernel.
+tablet compaction on 64-bit packed keys: output ranks by searchsorted
+(or the Pallas rank kernel), then one scatter of keys + payload.
+merge_sorted_device / merge_pair_device / merge_base_runs_device:
+traceable forms used per tablet inside the dist_ingest shard_map
+compaction programs. Their jnp path is ONE stable sort of the
+concatenated runs (equal to their stable merge:
+earlier runs win ties) — no scatter, and no gather unless the payload is
+wider than one column: a TPU runs both far slower than a sort at tablet
+sizes. Their Pallas path computes ranks in the kernel and scatters.
+Results are identical (asserted in tests).
 
-Backend policy matches the other store kernels: jnp reference on CPU,
-Pallas on TPU, with a documented VMEM cap (the full key lanes stay
-resident) falling back to the reference.
+Backend policy is kernels/common.py::resolve_backend: "auto" is the jnp
+reference on every platform (the rank kernel does not compile for TPU:
+its block shape breaks the (8, 128) tiling rule and its binary search
+gathers from VMEM — ROADMAP S8). An explicit "pallas" request runs the
+kernel (interpret mode off TPU) and raises ValueError past the VMEM cap
+(the full key lanes stay resident) instead of switching to the reference.
 """
 from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from ..common import pow2 as _pow2, split_key_lanes
+from ..common import pallas_interpret, pow2 as _pow2, resolve_backend, split_key_lanes
 from .merge_runs import merge_ranks_pallas
-from .ref import merge_ranks_keys, merge_ranks_ref
 
 # 2 lanes * 4 B * 1M keys = 8 MiB resident in VMEM.
 MAX_VMEM_KEYS = 1 << 20
 
 _SENTINEL64 = np.iinfo(np.int64).max
+
+
+def _check_vmem(n_keys: int) -> None:
+    """The rank kernel keeps every run's key lanes resident in VMEM."""
+    if n_keys > MAX_VMEM_KEYS:
+        raise ValueError(
+            f"merge_runs pallas: {n_keys} padded keys exceed the VMEM cap "
+            f"MAX_VMEM_KEYS={MAX_VMEM_KEYS}; use backend='ref'"
+        )
 
 
 def merge_sorted_runs(
@@ -39,20 +55,22 @@ def merge_sorted_runs(
     (keys [n], cols [n, w]) with n = sum n_i.
     """
     runs = [(np.asarray(k, np.int64), np.asarray(c)) for k, c in runs]
-    runs = [(k, c) for k, c in runs if k.size]
     if not runs:
         return np.empty(0, np.int64), np.empty((0, 0), np.int32)
+    # Width and dtype come from the input even when every run is empty.
+    w = runs[0][1].shape[1]
+    col_dtype = runs[0][1].dtype
+    runs = [(k, c) for k, c in runs if k.size]
+    if not runs:
+        return np.empty(0, np.int64), np.empty((0, w), col_dtype)
     if len(runs) == 1:
         return runs[0]
     k = len(runs)
-    w = runs[0][1].shape[1]
-    col_dtype = runs[0][1].dtype
     n_total = sum(kk.size for kk, _ in runs)
 
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if backend != "ref" and k * _pow2(max(kk.size for kk, _ in runs)) > MAX_VMEM_KEYS:
-        backend = "ref"
+    backend = resolve_backend(backend)
+    if backend == "pallas":
+        _check_vmem(k * _pow2(max(kk.size for kk, _ in runs)))
 
     if backend == "ref":
         # CPU: the same rank computation with unpadded numpy searchsorted
@@ -81,10 +99,10 @@ def merge_sorted_runs(
         keys_pad[i, : kk.size] = kk
         cols_pad[i, : kk.size] = cc
     hi, lo = split_key_lanes(keys_pad.reshape(-1))
-    interpret = jax.default_backend() != "tpu"
     ranks = np.asarray(
         merge_ranks_pallas(
-            jnp.asarray(hi.reshape(k, r)), jnp.asarray(lo.reshape(k, r)), interpret=interpret
+            jnp.asarray(hi.reshape(k, r)), jnp.asarray(lo.reshape(k, r)),
+            interpret=pallas_interpret(),
         )
     )
     # Scatter epilogue: ranks are a permutation of [0, k*r); sentinels
@@ -95,6 +113,20 @@ def merge_sorted_runs(
     out_cols = np.empty((k * r, w), col_dtype)
     out_cols[flat] = cols_pad.reshape(-1, w)
     return out_keys[:n_total], out_cols[:n_total]
+
+
+def sort_with_payload(keys, cols):
+    """Stable sort of keys (N,) with their payload rows cols (N, F) —
+    applied to concatenated sorted runs, their stable merge (earlier runs
+    win ties). Payloads of width <= 1 ride the sort as operands; wider
+    ones follow the sorted permutation by one row gather."""
+    f = cols.shape[-1]
+    if f <= 1:
+        out = lax.sort((keys,) + ((cols[:, 0],) if f else ()), num_keys=1, is_stable=True)
+        return out[0], (out[1][:, None] if f else cols)
+    iota = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    sorted_keys, perm = lax.sort((keys, iota), num_keys=1, is_stable=True)
+    return sorted_keys, cols[perm]
 
 
 def merge_pair_device(a_keys, a_cols, b_keys, b_cols, backend: str = "auto"):
@@ -109,7 +141,11 @@ def merge_pair_device(a_keys, a_cols, b_keys, b_cols, backend: str = "auto"):
     (Ca, W) / b_cols (Cb, W) travel with their keys. Returns the merged
     (Ca+Cb,) keys and (Ca+Cb, W) cols — all real keys first (stable:
     a-side wins ties), sentinels as a contiguous tail. Backend policy is
-    merge_sorted_device's (jnp reference on CPU, Pallas ranks on TPU)."""
+    merge_sorted_device's."""
+    if resolve_backend(backend) == "ref":
+        return sort_with_payload(
+            jnp.concatenate([a_keys, b_keys]), jnp.concatenate([a_cols, b_cols])
+        )
     ca, cb = a_keys.shape[0], b_keys.shape[0]
     w = a_cols.shape[-1]
     l2 = _pow2(max(ca, cb))
@@ -120,6 +156,25 @@ def merge_pair_device(a_keys, a_cols, b_keys, b_cols, backend: str = "auto"):
     pc = pc.at[0, :ca].set(a_cols).at[1, :cb].set(b_cols)
     mk, mc = merge_sorted_device(pk, pc, backend=backend)
     return mk[: ca + cb], mc[: ca + cb]
+
+
+def merge_base_runs_device(base_keys, base_cols, run_keys, run_cols, backend: str = "auto"):
+    """Whole major compaction of one tablet, traceable: the base (C,) /
+    (C, W) merged with K sentinel-masked runs (K, R) / (K, R, W). Returns
+    (C+K*R,) keys and (C+K*R, W) cols — the base wins ties, then earlier
+    runs; sentinels as a contiguous tail. On "auto" that is ONE stable
+    sort of the concatenation; the rank kernel merges the runs first and
+    then the result into the base (merge_pair_device), with the same
+    output."""
+    k, r = run_keys.shape
+    w = run_cols.shape[-1]
+    if resolve_backend(backend) == "ref":
+        return sort_with_payload(
+            jnp.concatenate([base_keys, run_keys.reshape(-1)]),
+            jnp.concatenate([base_cols, run_cols.reshape(k * r, w)]),
+        )
+    mk, mc = merge_sorted_device(run_keys, run_cols, backend=backend)
+    return merge_pair_device(base_keys, base_cols, mk, mc, backend=backend)
 
 
 def merge_window_keys(keys, start: int, length: int):
@@ -164,24 +219,19 @@ def merge_sorted_device(run_keys, run_cols, backend: str = "auto"):
     Returns the merged (K*R,) keys and (K*R, F) cols — sentinels as a
     contiguous tail.
 
-    Backend policy matches merge_sorted_runs: jnp searchsorted reference on
-    CPU, the Pallas rank kernel on TPU (interpret elsewhere), with the
-    VMEM-resident key-lane cap falling back to the reference. Ranks are
-    identical between backends (asserted in tests), so the choice never
-    changes results.
+    Backend policy matches merge_sorted_runs: "auto" is the jnp stable
+    sort (sort_with_payload); "pallas" runs the rank kernel (interpret mode off
+    TPU) and raises past the VMEM-resident key-lane cap. Both give the
+    same merge (asserted in tests), so the choice never changes results.
     """
     k, r = run_keys.shape
     f = run_cols.shape[-1]
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+    backend = resolve_backend(backend)
     r2 = _pow2(r)
-    if backend != "ref" and k * r2 > MAX_VMEM_KEYS:
-        backend = "ref"
+    if backend == "pallas":
+        _check_vmem(k * r2)
     if backend == "ref":
-        ranks = merge_ranks_keys(run_keys).reshape(-1)
-        out_keys = jnp.zeros((k * r,), run_keys.dtype).at[ranks].set(run_keys.reshape(-1))
-        out_cols = jnp.zeros((k * r, f), run_cols.dtype).at[ranks].set(run_cols.reshape(k * r, f))
-        return out_keys, out_cols
+        return sort_with_payload(run_keys.reshape(-1), run_cols.reshape(k * r, f))
     # Sentinel-pad each run to a power of two: added sentinels sort after
     # every real key, so real ranks are unchanged and sentinels (original
     # and pad) fill the permutation's tail. Scatter at the padded length,
@@ -191,8 +241,7 @@ def merge_sorted_device(run_keys, run_cols, backend: str = "auto"):
     padded = jnp.full((k, r2), sentinel, run_keys.dtype).at[:, :r].set(run_keys)
     padded_cols = jnp.zeros((k, r2, f), run_cols.dtype).at[:, :r].set(run_cols)
     hi, lo = _device_lanes(padded)
-    interpret = jax.default_backend() != "tpu"
-    ranks = merge_ranks_pallas(hi, lo, interpret=interpret).reshape(-1)
+    ranks = merge_ranks_pallas(hi, lo, interpret=pallas_interpret()).reshape(-1)
     out_keys = jnp.full((k * r2,), sentinel, run_keys.dtype).at[ranks].set(padded.reshape(-1))
     out_cols = jnp.zeros((k * r2, f), run_cols.dtype).at[ranks].set(padded_cols.reshape(k * r2, f))
     return out_keys[: k * r], out_cols[: k * r]

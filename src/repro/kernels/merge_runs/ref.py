@@ -1,9 +1,10 @@
-"""Pure-jnp oracle + device-side form of the k-way merge rank computation.
+"""Pure-jnp oracle of the k-way merge rank computation.
 
-merge_ranks_keys is traceable (jit / shard_map safe): the device ingest
-plane calls it per tablet inside the major-compaction shard_map program
-with int32 rev_ts keys; the Pallas kernel is its (hi, lo)-lane twin for
-TPU execution of the host tablets' 64-bit packed keys.
+merge_ranks_keys is traceable (jit / shard_map safe); merge_ranks_ref is
+its (hi, lo)-lane form, the oracle the Pallas rank kernel is tested
+against. The device plane's own merges do not rank: their jnp path is a
+stable sort (ops.py::sort_with_payload), because searchsorted's gather
+loop runs far slower than a sort on a TPU at tablet sizes.
 """
 from __future__ import annotations
 

@@ -1,10 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST precede every other import: jax locks the device count on first
-# init, and the multi-pod dry-run needs 512 placeholder host devices.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 record memory / cost / collective analysis.
 
@@ -17,6 +10,7 @@ present cells are skipped unless --force (the full sweep takes a while on
 one CPU core, so it is resumable)."""
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -200,6 +194,13 @@ def run_store_cell(mesh_kind: str, rows_per_tablet: int = 4_000_000) -> dict:
 
 
 def main() -> None:
+    # Must run before the first jax call creates the CPU client: the device
+    # count is fixed then, and the multi-pod dry-run needs 512 placeholder
+    # host devices. Set here, not at import, so importing this module (the
+    # tests import plan_cells) leaves the process's devices alone.
+    os.environ["XLA_FLAGS"] = " ".join(
+        (os.environ.get("XLA_FLAGS", ""), "--xla_force_host_platform_device_count=512")
+    ).strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -7,6 +7,7 @@ module-level mesh would lock the device count prematurely).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,10 +16,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     parallelism across the inter-pod (DCN-class) links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_dev_mesh(n_data: int = 1, n_model: int = 1):
-    """Small mesh for tests (requires XLA host-device override in a
-    subprocess; see tests/test_distributed.py)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    """Small mesh for tests and the one- and four-chip store paths (more
+    than one CPU device needs the XLA host-device override in a
+    subprocess; see tests/test_distributed.py). Axes are Auto, as
+    with_sharding_constraint hints in the model steps require."""
+    return jax.make_mesh(
+        (n_data, n_model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -17,7 +17,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import disable_x64
+
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import SHAPES, ModelConfig, ShapeConfig
@@ -98,11 +98,11 @@ class _CtxJit:
         self._rules = rules
 
     def lower(self, *args, **kw):
-        with dist_ctx.sharding_context(self._mesh, self._rules), disable_x64():
+        with dist_ctx.sharding_context(self._mesh, self._rules), jax.enable_x64(False):
             return self._fn.lower(*args, **kw)
 
     def __call__(self, *args, **kw):
-        with dist_ctx.sharding_context(self._mesh, self._rules), disable_x64():
+        with dist_ctx.sharding_context(self._mesh, self._rules), jax.enable_x64(False):
             return self._fn(*args, **kw)
 
 

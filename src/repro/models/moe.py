@@ -143,7 +143,7 @@ def moe_ffn(
 
 def _moe_ffn_shard_map(params, x, *, top_k, capacity_factor, act, mesh, dp_names):
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     b, s, d = x.shape
     e = params["router"].shape[1]
@@ -178,5 +178,5 @@ def _moe_ffn_shard_map(params, x, *, top_k, capacity_factor, act, mesh, dp_names
             P("model", None, None),
         ),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"], params["wo"])

@@ -15,8 +15,10 @@ on them, flushed before any long work):
     INCIDENT_DIR=<path>
 
 Exit code 0 on a clean run (incidents are an observability outcome, not
-a failure). The default SLOs are loose; CI induces a breach by passing
-an absurdly tight --ttfr-slo.
+a failure); 1 when a writer or session thread died or did not stop
+(each such thread is reported as a `THREAD_FAILED=` line). The default
+SLOs are loose; CI induces a breach by passing an absurdly tight
+--ttfr-slo.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ import argparse
 import sys
 import threading
 import time
+import traceback
 from typing import List
 
 import numpy as np
@@ -150,6 +153,23 @@ def main(argv=None) -> int:
 
     stop = threading.Event()
     served = [0] * args.sessions
+    failed: List[str] = []  # names of threads that died
+    failed_lock = threading.Lock()
+
+    def guarded(body):
+        """Thread target: a writer or session that raises is recorded and
+        turns the daemon's exit code non-zero (the traceback goes to
+        stderr) instead of dying silently."""
+
+        def run(*a) -> None:
+            try:
+                body(*a)
+            except BaseException:
+                traceback.print_exc()
+                with failed_lock:
+                    failed.append(threading.current_thread().name)
+
+        return run
 
     def writer_loop(wid: int) -> None:
         w = DistBatchWriter(store, plane, batch_rows=512, writer_id=wid)
@@ -178,16 +198,22 @@ def main(argv=None) -> int:
                     s.submit(scheme, t0, t1, tree).drain(timeout=60.0)
                     served[i] += 1
                 except RuntimeError:
+                    if not stop.is_set():
+                        raise
                     break  # service closed under us: clean shutdown race
         finally:
             if not s.closed:
                 s.close()
 
     threads: List[threading.Thread] = [
-        threading.Thread(target=writer_loop, args=(w,), name=f"writer-{w}", daemon=True)
+        threading.Thread(
+            target=guarded(writer_loop), args=(w,), name=f"writer-{w}", daemon=True
+        )
         for w in range(args.writers)
     ] + [
-        threading.Thread(target=session_loop, args=(i,), name=f"client-{i}", daemon=True)
+        threading.Thread(
+            target=guarded(session_loop), args=(i,), name=f"client-{i}", daemon=True
+        )
         for i in range(args.sessions)
     ]
     for t in threads:
@@ -198,6 +224,7 @@ def main(argv=None) -> int:
     stop.set()
     for t in threads:
         t.join(timeout=90.0)
+    hung = [t.name for t in threads if t.is_alive()]
     watchdog.stop()
     svc.close()
     endpoint.stop()
@@ -209,8 +236,13 @@ def main(argv=None) -> int:
     )
     for inc in incidents:
         print(f"INCIDENT={inc['bundle']} rule={inc['rule']}", flush=True)
-    return 0
+    for name in failed + hung:
+        print(f"THREAD_FAILED={name}", flush=True)
+    return 1 if failed or hung else 0
 
 
 if __name__ == "__main__":
+    from ..compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

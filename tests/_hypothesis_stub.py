@@ -7,7 +7,7 @@ skip every property test there, conftest.py installs this shim into
 `sys.modules['hypothesis']` when the real package is absent.
 
 It implements exactly the API surface the suite uses — `given`, `settings`,
-and the strategies `integers / floats / booleans / sampled_from / lists /
+`example`, and the strategies `integers / floats / booleans / sampled_from / lists /
 tuples / data` — as a deterministic random sweep: each decorated test runs
 `max_examples` times with values drawn from a per-test seeded numpy
 Generator. No shrinking, no database, no coverage-guided search; it is a
@@ -112,6 +112,19 @@ def settings(max_examples: int = _DEFAULT_MAX_EXAMPLES, deadline=None, **_kw):
     return deco
 
 
+def example(**kwargs):
+    """Decorator: an explicit case the given() runner always runs first
+    (real hypothesis keeps discovered failures this way). `.via()` is
+    accepted and ignored."""
+
+    def deco(fn):
+        fn._stub_examples = [kwargs] + list(getattr(fn, "_stub_examples", []))
+        return fn
+
+    deco.via = lambda _why: deco
+    return deco
+
+
 def given(*pos_strategies, **kw_strategies):
     """Decorator: run the test max_examples times with drawn arguments.
 
@@ -123,6 +136,7 @@ def given(*pos_strategies, **kw_strategies):
 
     def deco(fn):
         max_examples = getattr(fn, "_stub_max_examples", _DEFAULT_MAX_EXAMPLES)
+        examples = getattr(fn, "_stub_examples", [])
         sig = inspect.signature(fn)
         params = list(sig.parameters.values())
         bound_names = set(kw_strategies)
@@ -137,6 +151,8 @@ def given(*pos_strategies, **kw_strategies):
         def wrapper(*args, **kwargs):
             seed = zlib.crc32(f"{fn.__module__}.{fn.__qualname__}".encode())
             rng = np.random.default_rng(seed)
+            for ex in examples:
+                fn(*args, **kwargs, **ex)
             for _ in range(max_examples):
                 drawn = {name: s.draw(rng) for name, s in kw_strategies.items()}
                 for name, s in zip(pos_names, pos_strategies):
@@ -181,6 +197,7 @@ def install() -> None:
     mod = types.ModuleType("hypothesis")
     mod.given = given
     mod.settings = settings
+    mod.example = example
     mod.assume = assume
     mod.HealthCheck = HealthCheck
     mod.__version__ = __version__

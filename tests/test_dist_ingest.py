@@ -2,7 +2,7 @@
 exact-agreement, live incremental visibility, and backpressure telemetry."""
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.core import AggregateSpec, And, Eq, EventStore, Not, Or, web_proxy_schema
@@ -16,6 +16,7 @@ from repro.core.ingest import IngestMetrics
 from repro.core.query import QueryProcessor
 from repro.kernels.common import split_key_lanes
 from repro.kernels.merge_runs import (
+    merge_base_runs_device,
     merge_ranks_pallas,
     merge_ranks_ref,
     merge_sorted_device,
@@ -47,6 +48,7 @@ def _random_runs(rng, k, max_n, key_bits=53, dup_frac=0.3):
 
 @given(k=st.integers(2, 6), max_n=st.integers(1, 800), seed=st.integers(0, 2**31))
 @settings(max_examples=20, deadline=None)
+@example(k=2, max_n=1, seed=2).via("discovered failure: every run empty")
 def test_merge_sorted_runs_vs_numpy(k, max_n, seed):
     """Host merge == concat + stable argsort (the placeholder it retires),
     including duplicate keys and empty runs."""
@@ -99,6 +101,42 @@ def test_merge_sorted_device_pad_sentinels():
     np.testing.assert_array_equal(mk[:n_tot], real_k[order])
     np.testing.assert_array_equal(mc[:n_tot], real_c[order])
     assert (mk[n_tot:] == INT32_MAX).all()
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+def test_merge_base_runs_device_backends_agree(dtype):
+    """Major-compaction merge: the one-sort "auto" path and the pallas
+    two-stage path give the same arrays, equal to a stable sort of base
+    then runs (base wins ties, then earlier runs), sentinels last."""
+    rng = np.random.default_rng(11)
+    sent = np.iinfo(dtype).max
+    c, k, r, w = 96, 3, 32, 2
+    base_k = np.full(c, sent, dtype)
+    base_c = np.zeros((c, w), np.int32)
+    base_k[:70] = np.sort(rng.integers(0, 30, 70))
+    base_c[:70] = rng.integers(1, 100, (70, w))
+    run_k = np.full((k, r), sent, dtype)
+    run_c = np.zeros((k, r, w), np.int32)
+    ns = [20, 0, 32]
+    for i, n in enumerate(ns):
+        run_k[i, :n] = np.sort(rng.integers(0, 30, n))
+        run_c[i, :n] = rng.integers(100, 200, (n, w))
+    got = {
+        b: tuple(np.asarray(a) for a in merge_base_runs_device(
+            jnp.asarray(base_k), jnp.asarray(base_c),
+            jnp.asarray(run_k), jnp.asarray(run_c), backend=b))
+        for b in ("ref", "pallas")
+    }
+    np.testing.assert_array_equal(got["ref"][0], got["pallas"][0])
+    np.testing.assert_array_equal(got["ref"][1], got["pallas"][1])
+    real_k = np.concatenate([base_k[:70]] + [run_k[i, :n] for i, n in enumerate(ns)])
+    real_c = np.concatenate([base_c[:70]] + [run_c[i, :n] for i, n in enumerate(ns)])
+    order = np.argsort(real_k, kind="stable")
+    n_tot = real_k.size
+    mk, mc = got["ref"]
+    np.testing.assert_array_equal(mk[:n_tot], real_k[order])
+    np.testing.assert_array_equal(mc[:n_tot], real_c[order])
+    assert (mk[n_tot:] == sent).all() and mk.size == c + k * r
 
 
 def test_tablet_major_compaction_uses_merge(monkeypatch):
@@ -404,3 +442,32 @@ def test_published_store_survives_later_compactions():
     plane.publish()
     # The old view still reads, and still shows the old counts.
     np.testing.assert_array_equal(np.asarray(jax.device_get(ds.counts)), counts_before)
+
+
+def test_plane_state_keeps_its_layout_through_compactions():
+    """Every program hands the state back with the shapes, dtypes and
+    shardings it was created with: a drift (an int64 live count after a
+    major, a canonicalised sharding) would make the next call of every
+    step compile again, inside ingest."""
+    store = EventStore(web_proxy_schema())
+    plane = DistIngestPlane.for_store(
+        store, make_dev_mesh(1, 1), capacity=1 << 12, tablets_per_device=4,
+        mem_rows=64, max_runs=2,
+    )
+    g = plane.groups[0]
+    layout = g.programs.state_layout()
+    created = {k: v.sharding for k, v in g.state.items()}
+    rng = np.random.default_rng(0)
+    n = 4 * 64 * 4
+    plane.ingest(
+        rng.integers(0, 1 << 29, n).astype(np.int32),
+        rng.integers(0, 9, (n, 12)).astype(np.int32),
+        rng.integers(0, 4, n).astype(np.int32),
+    )
+    assert int(g._major_host.sum()) > 0
+    plane.compact_step()
+    plane.publish()
+    plane.compact()
+    for k, v in g.state.items():
+        assert (v.shape, v.dtype) == (layout[k][0], layout[k][1]), k
+        assert v.sharding == created[k], k

@@ -22,8 +22,9 @@ SCRIPT = textwrap.dedent(
     from repro.configs.base import ShapeConfig
     from repro.distributed.sharding import param_specs, zero1_specs
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_dev_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_dev_mesh(2, 4)
     out = {}
 
     # 1. Real multi-device train step: loss finite, params updated,

@@ -128,3 +128,38 @@ def test_combine_boundary_straddling():
     gk, gc = combine_sorted_counts(keys, cnt, backend="pallas")
     assert list(gk) == [7]
     assert list(gc) == [n]
+
+
+# ------------------------------------------------------------ backend policy
+def test_auto_never_selects_a_pallas_kernel():
+    """No Pallas kernel compiles for TPU yet, so `auto` is the reference
+    on every platform; an unknown backend name is refused."""
+    from repro.kernels.common import resolve_backend
+
+    assert resolve_backend("auto") == "ref"
+    assert resolve_backend("pallas") == "pallas"
+    with pytest.raises(ValueError):
+        resolve_backend("tpu")
+
+
+@pytest.mark.parametrize("which", ["merge_sorted_device", "merge_sorted_runs", "intersect_sorted"])
+def test_explicit_pallas_past_the_vmem_cap_raises(which):
+    """An explicit pallas request a kernel cannot hold in VMEM raises
+    instead of quietly switching to the reference."""
+    import jax.numpy as jnp
+
+    from repro.kernels.merge_intersect.ops import MAX_VMEM_KEYS as IX_CAP
+    from repro.kernels.merge_runs import MAX_VMEM_KEYS, merge_sorted_device, merge_sorted_runs
+
+    r = MAX_VMEM_KEYS // 2 + 1  # two runs pad past the cap
+    with pytest.raises(ValueError, match="VMEM"):
+        if which == "merge_sorted_device":
+            merge_sorted_device(
+                jnp.zeros((2, r), jnp.int32), jnp.zeros((2, r, 0), jnp.int32), backend="pallas"
+            )
+        elif which == "merge_sorted_runs":
+            run = (np.arange(r, dtype=np.int64), np.zeros((r, 1), np.int32))
+            merge_sorted_runs([run, run], backend="pallas")
+        else:
+            big = np.arange(IX_CAP + 1, dtype=np.int64)
+            intersect_sorted(big, big, backend="pallas")

@@ -1066,3 +1066,28 @@ def test_serve_daemon_main_produces_incident(tmp_path, capsys):
     snap = json.loads((bundles[0] / "metrics.json").read_text())
     assert snap["kind"] == "obs_metrics_snapshot"
     assert "INCIDENT=" in out
+
+
+def test_serve_daemon_main_fails_when_a_thread_dies(tmp_path, capsys, monkeypatch):
+    """A writer thread that raises turns the daemon's exit code non-zero
+    and is named in a THREAD_FAILED= line, instead of dying silently."""
+    from repro.core.dist_ingest import DistBatchWriter
+    from repro.serve_db.__main__ import main
+
+    def broken_add(self, *a, **kw):
+        raise RuntimeError("injected writer fault")
+
+    monkeypatch.setattr(DistBatchWriter, "add", broken_add)
+    try:
+        rc = main(
+            [
+                "--rows", "1200", "--sessions", "1", "--writers", "1",
+                "--duration", "1.0", "--incident-dir", str(tmp_path / "inc"),
+                "--groups", "1", "--tablets-per-device", "2",
+            ]
+        )
+    finally:
+        obs.flight_disable()
+        obs.flight_clear()
+    assert rc == 1
+    assert "THREAD_FAILED=writer-0" in capsys.readouterr().out

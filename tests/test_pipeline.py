@@ -85,3 +85,19 @@ def test_tokenizer_batches(staged_files):
     assert batch.shape == (4, 64)
     assert batch.dtype == np.int32
     assert batch.min() >= 0 and batch.max() < 8192
+
+
+def test_gen_lines_spell_out_gen_codes():
+    """One data model: the text lines are the coded events spelled out
+    through the vocabulary, field for field, under the same seed."""
+    from repro.pipeline.sources import FIELDS, parse_web_proxy_lines
+
+    lines = SyntheticWebProxySource(n_domains=50, seed=3).gen_lines(400, 0, 3600)
+    src = SyntheticWebProxySource(n_domains=50, seed=3)
+    ts, codes = src.gen_codes(400, 0, 3600)
+    vocab = src.vocabulary()
+    got_ts, cols = parse_web_proxy_lines(lines)
+    np.testing.assert_array_equal(got_ts, ts)
+    for j, f in enumerate(FIELDS):
+        assert cols[f] == vocab[f][codes[:, j]].tolist(), f
+    assert (codes[:, FIELDS.index("domain")] == codes[:, FIELDS.index("referer")]).all()
