@@ -1,0 +1,7 @@
+"""1 - (union of device-op intervals, mean over chips) / window, from the profiler trace of the window."""
+
+
+def read(art):
+    if art.trace is None or not art.queries:
+        return None
+    return art.trace["idle_share"]
