@@ -433,7 +433,7 @@ class _PlanePrograms:
             new_n = jnp.minimum(want, jnp.int32(m))
             return mem_k, mem_c, new_n, new_n - n, want - new_n
 
-        def device_fn(st, b_rts, b_cols, b_tab):
+        def plane_append(st, b_rts, b_cols, b_tab):
             dev = _linear_device_index(mesh)
             # Index/aggregate entries synthesized from the event rows —
             # index maintenance rides the ingest path (module docstring).
@@ -487,7 +487,7 @@ class _PlanePrograms:
             return jax.vmap(one, in_axes=(0, 0))(idx, st)
 
         smapped = shard_map(
-            device_fn,
+            plane_append,
             mesh=mesh,
             in_specs=(self._specs(names), P(None), P(None, None), P(None)),
             out_specs=self._specs(names),
@@ -505,7 +505,7 @@ class _PlanePrograms:
         families = self.families
         names = self._minor_names()
 
-        def device_fn(st):
+        def plane_minor(st):
             def one(loc):
                 nr = loc["n_runs"]
                 # All families flush in lockstep: a tablet holds event rows
@@ -531,7 +531,7 @@ class _PlanePrograms:
             return jax.vmap(one)(st)
 
         smapped = shard_map(
-            device_fn,
+            plane_minor,
             mesh=mesh,
             in_specs=(self._specs(names),),
             out_specs=self._specs(names),
@@ -550,7 +550,7 @@ class _PlanePrograms:
         backend = self.kernel_backend
         run_names, base_names = self._major_names()
 
-        def device_fn(rst, bst):
+        def plane_major(rst, bst):
             def one(rloc, bloc):
                 nr = rloc["n_runs"]
                 do = nr > 0
@@ -602,7 +602,7 @@ class _PlanePrograms:
             return jax.vmap(one)(rst, bst)
 
         smapped = shard_map(
-            device_fn,
+            plane_major,
             mesh=mesh,
             in_specs=(self._specs(run_names), self._specs(base_names)),
             out_specs=(self._specs(run_names), self._specs(base_names)),
@@ -639,7 +639,7 @@ class _PlanePrograms:
         backend = self.kernel_backend
         run_names, base_names = self._major_names()
 
-        def device_fn(rst, bst):
+        def plane_fold_one(rst, bst):
             def one(rloc, bloc):
                 nr = rloc["n_runs"]
                 do = nr > 0
@@ -686,7 +686,7 @@ class _PlanePrograms:
             return jax.vmap(one)(rst, bst)
 
         smapped = shard_map(
-            device_fn,
+            plane_fold_one,
             mesh=mesh,
             in_specs=(self._specs(run_names), self._specs(base_names)),
             out_specs=(self._specs(run_names), self._specs(base_names)),
@@ -726,7 +726,7 @@ class _PlanePrograms:
             out_specs[f"{p}_sealed_c"] = P(self.axes, None, None)
             out_specs[f"{p}_sealed_n"] = P(self.axes)
 
-        def device_fn(st):
+        def plane_seal(st):
             def one(loc):
                 out = {}
                 for f in families:
@@ -751,7 +751,7 @@ class _PlanePrograms:
             return jax.vmap(one)(st)
 
         smapped = shard_map(
-            device_fn,
+            plane_seal,
             mesh=mesh,
             in_specs=(self._specs(names),),
             out_specs=out_specs,
@@ -1666,8 +1666,9 @@ class DistBatchWriter(BatchWriter):
             # Same contract as EventStore.ingest_encoded — out-of-range
             # timestamps must not silently wrap into negative rev_ts.
             raise ValueError("timestamp out of 30-bit store range")
-        cols = self.store.encode_events(ts, values)
         n = len(ts)
+        with span("ingest.encode", cat="ingest", rows=n):
+            cols = self.store.encode_events(ts, values)
         # Row hash decides the tablet: content + per-writer nonce, so
         # identical events still spread uniformly (the paper's random
         # sharding; shard id is implicit in tablet choice here).

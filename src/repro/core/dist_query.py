@@ -962,7 +962,7 @@ def build_density_step(mesh: Mesh, runs: bool = False):
     of the host aggregate table."""
     axes = tuple(mesh.axis_names)
 
-    def fn(*args):
+    def query_density(*args):
         if runs:
             (ag_keys, ag_vals, ag_run_k, ag_run_c, ag_run_n,
              ag_mem_k, ag_mem_c, ag_mem_n, lo, hi) = args
@@ -999,7 +999,7 @@ def build_density_step(mesh: Mesh, runs: bool = False):
         in_specs += _ag_level_specs(axes)
     in_specs += (P(), P())
     smapped = shard_map(
-        fn,
+        query_density,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),

@@ -35,7 +35,6 @@ from .trace import (
     enabled,
     get_tracer,
     span,
-    traced,
 )
 from .export import (
     chrome_trace,
@@ -89,7 +88,6 @@ __all__ = [
     "span",
     "summary",
     "to_prometheus_text",
-    "traced",
     "validate_chrome_trace",
     "write_chrome_trace",
     "write_metrics_json",

@@ -68,6 +68,7 @@ class OwnedLock:
         self._seg_t0: Optional[float] = None
         self._owner: Optional[str] = None
         self._owner_tid: int = 0
+        self._hold_wait = 0.0  # the current hold's acquire wait
         with _LOCKS_LOCK:
             _LOCKS.add(self)
 
@@ -86,6 +87,7 @@ class OwnedLock:
                 self._seg_t0 = now
                 self._owner = owner
                 self._owner_tid = threading.get_ident()
+                self._hold_wait = waited
         return ok
 
     def release(self) -> None:
@@ -94,14 +96,15 @@ class OwnedLock:
             self._charge_segment(now)
             if self._hold_t0 is not None:
                 self.total_held += now - self._hold_t0
-            t0, tid, owner = self._hold_t0, self._owner_tid, self._owner
+            t0, tid, owner, waited = self._hold_t0, self._owner_tid, self._owner, self._hold_wait
             self._hold_t0 = None
             self._seg_t0 = None
             self._owner = None
         self._lock.release()
         if t0 is not None and _trace._tracer.enabled:
             _trace._tracer.add_complete(
-                f"lock/{self.name}", t0, now - t0, cat="lock", tid=tid, owner=owner or "unknown"
+                f"lock/{self.name}", t0, now - t0, cat="lock", tid=tid,
+                owner=owner or "unknown", wait_s=waited,
             )
 
     def _charge_segment(self, now: float) -> None:

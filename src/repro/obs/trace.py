@@ -29,11 +29,10 @@ tracing affordable under sustained serve-plane load.
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 from .flight import get_flight
 
@@ -45,7 +44,6 @@ __all__ = [
     "enabled",
     "get_tracer",
     "span",
-    "traced",
 ]
 
 
@@ -97,7 +95,12 @@ class _DropSpan:
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "cat", "args", "sid", "parent", "tid", "t0", "fence_s")
+    """A kept span. Besides its record, it opens a
+    ``jax.profiler.TraceAnnotation`` of its name on the same thread, so a
+    profiler trace shows the program's spans on the device trace's clock
+    (a no-op while no profiler trace is being collected)."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "sid", "parent", "tid", "t0", "fence_s", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict[str, Any]) -> None:
         self.tracer = tracer
@@ -109,8 +112,13 @@ class _Span:
         self.tid = 0
         self.t0 = 0.0
         self.fence_s = 0.0
+        self.ann = None
 
     def __enter__(self) -> "_Span":
+        import jax
+
+        self.ann = jax.profiler.TraceAnnotation(self.name)
+        self.ann.__enter__()
         tr = self.tracer
         self.sid = tr._next_sid()
         stack = tr._stack()
@@ -127,6 +135,7 @@ class _Span:
         if stack and stack[-1] is self:
             stack.pop()
         self.tracer._record(self, t1 - self.t0)
+        self.ann.__exit__(None, None, None)
 
     def fence(self, x: object) -> object:
         """Block until a jax value is ready; the wait is charged to this
@@ -309,24 +318,6 @@ def span(name: str, cat: str = "", **args: object):
             return fr.span(name, cat, dict(args) if args else None)
         return _NULL
     return _tracer.span(name, cat, **args)
-
-
-def traced(name: Optional[str] = None, cat: str = "") -> Callable:
-    """Decorator form of :func:`span`."""
-
-    def deco(fn: Callable) -> Callable:
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a: object, **kw: object):
-            if not _tracer.enabled:
-                return fn(*a, **kw)
-            with _tracer.span(label, cat):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
 
 
 def enable(sample: Optional[float] = None) -> None:

@@ -220,19 +220,91 @@ def test_traced_decorator_and_args():
     obs.enable()
     obs.clear()
     try:
-
-        @obs.traced("deco.fn", cat="t")
-        def fn(x):
-            return x + 1
-
-        assert fn(1) == 2
+        with obs.span("plain.fn", cat="t"):
+            pass
         with obs.span("with_args", cat="t", k=3) as sp:
             sp.set(result=9)
     finally:
         obs.disable()
     recs = {r["name"]: r for r in obs.get_tracer().records}
-    assert "deco.fn" in recs
+    assert recs["plain.fn"]["args"] == {}
     assert recs["with_args"]["args"] == {"k": 3, "result": 9}
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation and logs its verbs."""
+
+    log = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+@pytest.mark.parametrize("mode", ["kept", "disabled", "sampled_out"])
+def test_kept_span_opens_one_profiler_annotation(monkeypatch, mode):
+    """A kept span opens and closes exactly one TraceAnnotation of its
+    name on its own thread; a disabled or sampled-out span opens none."""
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    _CountingAnnotation.log = []
+    if mode != "disabled":
+        obs.enable(sample=0.5 if mode == "sampled_out" else None)
+    obs.clear()
+    try:
+        if mode == "sampled_out":
+            with obs.span("first.root", cat="t"):  # the sampler keeps the 1st root
+                pass
+            _CountingAnnotation.log = []
+        with obs.span("ann.outer", cat="t") as sp:
+            with obs.span("ann.inner", cat="t"):
+                sp.set(n=1)
+    finally:
+        obs.disable()
+    me = threading.get_ident()
+    if mode == "kept":
+        assert _CountingAnnotation.log == [
+            ("enter", "ann.outer", me), ("enter", "ann.inner", me),
+            ("exit", "ann.inner", me), ("exit", "ann.outer", me),
+        ]
+    else:
+        assert _CountingAnnotation.log == []
+
+
+def test_ingest_encode_span_once_per_flushed_batch():
+    """DistBatchWriter times its dictionary encode as one ingest.encode
+    span per flushed batch, carrying the batch's rows."""
+    rng = np.random.default_rng(5)
+    n = 2_500
+    ts = np.sort(rng.integers(0, T_SPAN, n))
+    vals = {"domain": rng.choice(["a.com", "b.com"], size=n).tolist(),
+            "status": rng.choice(["200", "404"], size=n).tolist()}
+    store = EventStore(web_proxy_schema(), n_shards=2)
+    plane = DistIngestPlane.for_store(
+        store, make_dev_mesh(1, 1), capacity=4 * n, tablets_per_device=2,
+        mem_rows=512, max_runs=4, append_rows=256,
+    )
+    w = DistBatchWriter(store, plane, batch_rows=1024)
+    obs.enable()
+    obs.clear()
+    try:
+        for off in range(0, n, 1024):  # each full batch flushes on its add
+            w.add(ts[off:off + 1024], {k: v[off:off + 1024] for k, v in vals.items()})
+        w.close()  # and the rest here
+    finally:
+        obs.disable()
+    enc = [r for r in obs.get_tracer().records if r["name"] == "ingest.encode"]
+    assert [r["args"]["rows"] for r in enc] == [1024, 1024, n - 2048]
+    assert all(r["cat"] == "ingest" and r["dur"] > 0 for r in enc)
+    flushes = {r["sid"] for r in obs.get_tracer().records if r["name"] == "ingest.flush"}
+    assert {e["parent"] for e in enc} == flushes and len(flushes) == 3
 
 
 def test_chrome_trace_schema():
@@ -669,6 +741,36 @@ def test_owned_lock_books_acquire_wait():
     assert merged["total_wait_s"] == snap["total_wait_s"]
     lk.reset()
     assert lk.snapshot()["total_wait_s"] == 0.0
+
+
+def test_lock_hold_span_carries_its_acquire_wait():
+    """With tracing on, each hold's lock/<name> span carries the wait that
+    hold booked in wait_by_owner_s, so a trace window can sum the waits."""
+    lk = obs.OwnedLock("t_wait_span_lock")
+    started = threading.Event()
+
+    def holder():
+        with lk.hold("hog"):
+            started.set()
+            time.sleep(0.1)
+
+    obs.enable()
+    obs.clear()
+    try:
+        t = threading.Thread(target=holder)
+        t.start()
+        started.wait()
+        with lk.hold("waiter"):
+            pass
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        obs.disable()
+    spans = {r["args"]["owner"]: r for r in obs.get_tracer().records
+             if r["name"] == "lock/t_wait_span_lock"}
+    books = lk.snapshot()["wait_by_owner_s"]
+    assert spans["waiter"]["args"]["wait_s"] == books["waiter"] > 0.05
+    assert spans["hog"]["args"]["wait_s"] == books["hog"] < 0.05
 
 
 # ------------------------------------------------------------ flight recorder

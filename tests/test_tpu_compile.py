@@ -9,7 +9,8 @@ widths (8 tablets, all 12 web-proxy fields indexed, 4 run slots) with the
 per-tablet capacity cut to 2^14 and memtables to 128 rows, and every
 program compiles concurrently, so the file runs in well under a minute;
 chip_smoke.py runs the same programs at 2^18 rows and 4096-row memtables
-on the chip.
+on the chip. The same programs are also lowered on the CPU, to check the
+module name each shows in a device trace.
 """
 import os
 from concurrent.futures import ThreadPoolExecutor
@@ -176,3 +177,29 @@ def test_plane_program_compiles_for_v5e(compiled, which):
 def test_query_program_compiles_for_v5e(compiled, which):
     (fut,) = compiled[which]
     _fits(fut.result())
+
+
+# Each program's module name, which a device trace's "XLA Modules" line
+# shows as jit_<name>: the benchmark's per-program device time is keyed by it.
+MODULE_NAMES = {
+    "append": "plane_append", "minor": "plane_minor", "fold_one": "plane_fold_one",
+    "major": "plane_major", "seal": "plane_seal", "scan": "tablet_scan",
+    "index": "tablet_ix", "density": "query_density", "aggregate": "tablet_agg",
+}
+
+
+@pytest.fixture(scope="module")
+def cpu_programs():
+    """Every program at the file's shapes on a one-device CPU mesh, to lower
+    (no TPU library is loaded)."""
+    from repro.launch.mesh import make_dev_mesh
+
+    pr = make_programs(make_dev_mesh(1, 1))
+    return {name: (step, args) for name, step, args in plane_programs(pr) + query_programs(pr)}
+
+
+@pytest.mark.parametrize("which", sorted(MODULE_NAMES))
+def test_program_lowers_to_its_stable_module_name(cpu_programs, which):
+    step, args = cpu_programs[which]
+    head = step.lower(*args).as_text().split("\n", 1)[0]
+    assert head.startswith(f"module @jit_{MODULE_NAMES[which]} ")
