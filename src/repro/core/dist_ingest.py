@@ -12,9 +12,10 @@ programs over device-resident state:
              tablet picks its rows out of the replicated batch and
              scatter-appends them into its memtable slab
     minor    per-tablet memtable sort into the next sorted-run slot
-    major    k-way merge of runs + base (kernels/merge_runs: one
-             stable sort, or the Pallas rank kernel when a caller asks
-             for kernel_backend="pallas") —
+    major    k-way merge of runs + the base slab's live head
+             (kernels/merge_runs: one stable sort, or the Pallas rank
+             kernel when a caller asks for kernel_backend="pallas"; the
+             host mirror picks the head, pow2-bucketed) —
              BLOCKING the writer that tripped it, which is the paper's
              backpressure, reproduced on the mesh
 
@@ -362,6 +363,24 @@ class _PlanePrograms:
         log2-bounded, clamped to the slab capacity."""
         return int(min(max(_pow2(max(fill_max, 1)), 8), self.mem_rows))
 
+    def major_buckets(self) -> Tuple[int, ...]:
+        """Every sort_rows value _major_bucket can return: max_runs x
+        mem_rows, then each power of two above it, up to capacity (the
+        last possibly not a power of two)."""
+        out, rows = [], self.max_runs * self.mem_rows
+        while True:
+            out.append(min(rows, self.capacity))
+            if rows >= self.capacity:
+                return tuple(out)
+            rows = _pow2(rows + 1)
+
+    def _major_bucket(self, live_max: int) -> int:
+        """Event rows of each base slab a major must sort to hold every
+        tablet's base + run rows (at most live_max): the smallest of
+        major_buckets() that holds them, so the number of distinct major
+        compilations is log2-bounded; the whole slab past its capacity."""
+        return next((b for b in self.major_buckets() if b >= live_max), self.capacity)
+
     # ----------------------------------------------------------- step cache
     def _get_step(self, key, build):
         """Shared compile cache: two groups' (or two writers') first
@@ -379,8 +398,10 @@ class _PlanePrograms:
     def minor_step(self):
         return self._get_step("minor", self._build_minor)
 
-    def major_step(self):
-        return self._get_step("major", self._build_major)
+    def major_step(self, sort_rows: int):
+        return self._get_step(
+            ("major", sort_rows), lambda: self._build_major(sort_rows)
+        )
 
     def fold_one_step(self):
         return self._get_step("fold_one", self._build_fold_one)
@@ -391,8 +412,8 @@ class _PlanePrograms:
         )
 
     def precompile(self) -> None:
-        """Compile the minor, fold_one and major programs and every seal
-        bucket CONCURRENTLY, ahead of their first call. XLA compiles
+        """Compile the minor and fold_one programs and every major and
+        seal bucket CONCURRENTLY, ahead of their first call. XLA compiles
         outside the GIL and a jitted function's later call with the same
         shapes and shardings reuses the executable, so startup pays about
         the slowest compile instead of the sum (on a v5e each seal bucket
@@ -402,8 +423,7 @@ class _PlanePrograms:
         jobs = [
             (self.minor_step(), (self.abstract_state(self._minor_names()),)),
             (self.fold_one_step(), folds),
-            (self.major_step(), folds),
-        ] + [
+        ] + [(self.major_step(r), folds) for r in self.major_buckets()] + [
             (self.seal_step(r), (self.abstract_state(self._seal_names()),))
             for r in self.seal_buckets()
         ]
@@ -542,13 +562,27 @@ class _PlanePrograms:
         # a donated minor would delete arrays a caller may still hold.
         return jax.jit(smapped, out_shardings=self._shardings(self._specs(names)))
 
-    def _build_major(self):
+    def _build_major(self, sort_rows: int):
+        """Major compaction over the first `sort_rows` slots of each event
+        base slab (scaled per family: ix/ag slabs are n_indexed x wider)
+        — O(live fill), not O(capacity). The host sizes the bucket from
+        its exact fill mirrors (_major_bucket), so every live entry lies
+        in the head and every slot past it holds the sentinel before and
+        after the merge: the program rewrites the head and keeps the
+        tail, and the state equals a whole-slab major's bit for bit.
+        sort_rows == capacity is the whole-slab major."""
         from ..kernels.merge_runs import merge_base_runs_device
 
         mesh, k = self.mesh, self.max_runs
         families = self.families
         backend = self.kernel_backend
         run_names, base_names = self._major_names()
+        # Per-family head length: ix/ag entries never exceed n_indexed x
+        # the event rows (dedup and sum only shrink them).
+        heads = {
+            f.name: int(min(sort_rows * (f.capacity // self.capacity), f.capacity))
+            for f in families
+        }
 
         def plane_major(rst, bst):
             def one(rloc, bloc):
@@ -557,9 +591,9 @@ class _PlanePrograms:
                 out_r = dict(rloc)
                 out_b = {}
                 for f in families:
-                    p, m, c = f.name, f.mem_rows, f.capacity
-                    # Base + K runs (m rows each) in one merge: the base
-                    # wins ties, then earlier runs.
+                    p, m, h = f.name, f.mem_rows, heads[f.name]
+                    # Base head + K runs (m rows each) in one merge: the
+                    # base wins ties, then earlier runs.
                     rn = rloc[f"{p}_run_n"]
                     bk, bc, bn = bloc[f"{p}_base_k"], bloc[f"{p}_base_c"], bloc[f"{p}_base_n"]
                     # Mask stale slots/rows (run_n is authoritative; slots
@@ -567,7 +601,7 @@ class _PlanePrograms:
                     within = jnp.arange(m, dtype=jnp.int32)[None, :] < rn[:, None]
                     ck = jnp.where(within, rloc[f"{p}_run_k"], f.sentinel)
                     cc = jnp.where(within[..., None], rloc[f"{p}_run_c"], 0)
-                    fk, fc = merge_base_runs_device(bk, bc, ck, cc, backend=backend)
+                    fk, fc = merge_base_runs_device(bk[:h], bc[:h], ck, cc, backend=backend)
                     if f.combine == "sum":
                         # Aggregate family: sum duplicate (field, value,
                         # bucket) keys — Accumulo's combiner at compaction
@@ -588,10 +622,17 @@ class _PlanePrograms:
                         )
                     else:
                         total = bn + rn.sum(dtype=jnp.int32)
-                    new_bn = jnp.where(do, jnp.minimum(total, jnp.int32(c)), bn)
-                    lost = jnp.where(do, total - jnp.minimum(total, jnp.int32(c)), 0)
-                    out_b[f"{p}_base_k"] = jnp.where(do, fk[:c], bk)
-                    out_b[f"{p}_base_c"] = jnp.where(do, fc[:c], bc)
+                    # Entries past the head count as overflow: at the top
+                    # bucket that is the slab capacity, below it never
+                    # happens (the host's bucket holds every live entry).
+                    new_bn = jnp.where(do, jnp.minimum(total, jnp.int32(h)), bn)
+                    lost = jnp.where(do, total - jnp.minimum(total, jnp.int32(h)), 0)
+                    out_b[f"{p}_base_k"] = jnp.concatenate(
+                        [jnp.where(do, fk[:h], bk[:h]), bk[h:]]
+                    )
+                    out_b[f"{p}_base_c"] = jnp.concatenate(
+                        [jnp.where(do, fc[:h], bc[:h]), bc[h:]]
+                    )
                     out_b[f"{p}_base_n"] = new_bn
                     out_r[f"{p}_run_n"] = jnp.where(do, jnp.zeros_like(rn), rn)
                     out_r[f"{p}_overflow"] = rloc[f"{p}_overflow"] + lost
@@ -857,9 +898,16 @@ class TabletGroup:
             self._gen["mem"] += 1  # memtables drained
             self._gen["runs"] += 1  # run slabs gained a slot
 
-    def _run_major(self) -> None:  # holds: lock
+    def _run_major(self) -> int:  # holds: lock
+        """Fold every tablet's runs into its base, sorting only the base
+        slab's head that holds the live fill. Returns that head's length
+        in event rows (the bucket sorted)."""
         pr = self.programs
-        step = pr.major_step()
+        # Exact from the host mirrors: rows appended minus rows still in
+        # the memtable are the base + run rows (plus any the base lost to
+        # overflow, which only raises the bucket).
+        sort_rows = pr._major_bucket(int((self._rows_host - self._fill).max()))
+        step = pr.major_step(sort_rows)
         run_names, base_names = pr._major_names()
         out_r, out_b = step(self._sub(run_names), self._sub(base_names))
         self.state.update(out_r)
@@ -869,6 +917,7 @@ class TabletGroup:
             self._gen["runs"] += 1
             self._gen["base"] += 1
         self._runs_host[:] = 0
+        return sort_rows
 
     def _run_fold_one(self) -> None:  # holds: lock
         """One increment: every tablet with runs folds its top run slot
@@ -943,8 +992,11 @@ class TabletGroup:
                     # hold is fold work, not append work.
                     t0 = time.perf_counter()
                     with self.lock.reowner("fold_increment"):
-                        with span("ingest.major", cat="ingest", group=self.gid):
-                            self._run_major()
+                        with span(
+                            "ingest.major", cat="ingest", group=self.gid,
+                            capacity_rows=pr.capacity,
+                        ) as sp:
+                            sp.set(sort_rows=self._run_major())
                             jax.block_until_ready(self.state["ev_base_n"])
                     blocked += time.perf_counter() - t0
                     self._m_folds.inc(source="ingest")
@@ -1061,6 +1113,16 @@ class TabletGroup:
             self._run_minor()
             self._run_fold_one()
             self._run_major()
+            # Every run is folded now, so each bucket's major is a device
+            # no-op: run each once, and no later major loads a program.
+            # One at a time: each returns a copy of the runs and bases,
+            # and queued back to back their copies would all be live.
+            pr = self.programs
+            run_names, base_names = pr._major_names()
+            for rows in pr.major_buckets():
+                jax.block_until_ready(
+                    pr.major_step(rows)(self._sub(run_names), self._sub(base_names))
+                )
             if staged:
                 self._dirty = True
                 self._m_folds.inc(source="explicit")
@@ -1474,13 +1536,13 @@ class DistIngestPlane:
 
     def warm_compaction(self) -> None:
         """Pre-compile (and once-execute) every compaction program —
-        minor flush, incremental fold step, full major — so no later
-        background increment or blocking major pays an XLA compile (a
-        cold fold program otherwise lands its whole compile time inside
-        one \"bounded\" increment). Runs the real programs on each
-        group's current state: anything staged gets drained exactly like
-        compact(), and is attributed the same way; on a drained plane
-        all three are device no-ops."""
+        minor flush, incremental fold step, the major at every fill
+        bucket — so no later background increment or blocking major pays
+        an XLA compile (a cold fold program otherwise lands its whole
+        compile time inside one \"bounded\" increment). Runs the real
+        programs on each group's current state: anything staged gets
+        drained exactly like compact(), and is attributed the same way;
+        on a drained plane all of them are device no-ops."""
         for g in self.groups:
             g.warm_compaction()
 

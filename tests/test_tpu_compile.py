@@ -59,7 +59,8 @@ def _replicated(mesh, shape, dtype):
 
 def plane_programs(pr):
     """(name, jitted step, abstract args) of every ingest-plane program:
-    append, minor, fold_one, major and the seal."""
+    append, minor, fold_one, the major at its top and smallest fill
+    buckets, and the seal."""
     b, f = pr.append_rows, pr.n_fields
     run_names, base_names = pr._major_names()
     folds = (pr.abstract_state(run_names), pr.abstract_state(base_names))
@@ -72,7 +73,11 @@ def plane_programs(pr):
         )),
         ("minor", pr.minor_step(), (pr.abstract_state(pr._minor_names()),)),
         ("fold_one", pr.fold_one_step(), folds),
-        ("major", pr.major_step(), folds),
+        ("major", pr.major_step(pr.capacity), folds),
+        # The major's smallest fill bucket beside its top one (the whole
+        # slab): every bucket between is the same program at another
+        # sort length.
+        ("major_small", pr.major_step(pr.major_buckets()[0]), folds),
     ]
     # One seal bucket (the largest): every bucket is the same program at
     # another sort length, and each is a separate TPU sort compile.
@@ -166,7 +171,7 @@ def _fits(compiled) -> int:
     return used
 
 
-@pytest.mark.parametrize("which", ["append", "minor", "fold_one", "major", "seal"])
+@pytest.mark.parametrize("which", ["append", "minor", "fold_one", "major", "major_small", "seal"])
 def test_plane_program_compiles_for_v5e(compiled, which):
     assert compiled[which]
     for fut in compiled[which]:
@@ -183,7 +188,8 @@ def test_query_program_compiles_for_v5e(compiled, which):
 # shows as jit_<name>: the benchmark's per-program device time is keyed by it.
 MODULE_NAMES = {
     "append": "plane_append", "minor": "plane_minor", "fold_one": "plane_fold_one",
-    "major": "plane_major", "seal": "plane_seal", "scan": "tablet_scan",
+    "major": "plane_major", "major_small": "plane_major", "seal": "plane_seal",
+    "scan": "tablet_scan",
     "index": "tablet_ix", "density": "query_density", "aggregate": "tablet_agg",
 }
 
